@@ -306,7 +306,7 @@ SUBSYSTEM_DOCS: dict[str, dict] = {
                    "decode1_words_bytesliced", "PANEL_TEMP_ALIVE_FRACTION",
                    "pl.when", "PANEL_XOR_BUDGET",
                    "PANEL_SUBLAUNCH_XOR_BUDGET", "sublaunch_count",
-                   "input_output_aliases", "-compile-cache-dir",
+                   "input_output_aliases", "default_compile_cache",
                    "prewarm_ladder"),
     },
     "wire": {

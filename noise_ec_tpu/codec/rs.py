@@ -212,7 +212,7 @@ class ReedSolomon:
         self._breaker.record_failure()
         ensure_codec_prober()
         record_codec_fallback("error")
-        _rslog.warning(
+        _rslog.error(
             "device codec dispatch failed twice (%s); breaker %s — "
             "degrading to the golden host codec", last_exc,
             self._breaker.state(),

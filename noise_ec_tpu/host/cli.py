@@ -36,15 +36,13 @@ log = logging.getLogger("noise_ec_tpu.host.cli")
 
 def _kernel_label(backend: str) -> str:
     """The kernel tier actually serving this node, for the
-    noise_ec_build_info deployment-identity gauge."""
+    noise_ec_build_info deployment-identity gauge (raises like the codec
+    itself when a device node finds no accelerator)."""
     if backend != "device":
         return "numpy"
-    try:
-        import jax
+    from noise_ec_tpu.ops.dispatch import _resolve_kernel
 
-        return "pallas" if jax.default_backend() == "tpu" else "xla"
-    except Exception:  # noqa: BLE001 — identity gauge must not kill startup
-        return "unknown"
+    return _resolve_kernel("auto")
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -99,19 +97,6 @@ def build_parser() -> argparse.ArgumentParser:
         "the stats endpoint serves the last N seconds as flamegraph-ready "
         "collapsed text (without this flag the sampler starts lazily on "
         "the first /profile request)",
-    )
-    p.add_argument(
-        "-compile-cache-dir",
-        default="",
-        metavar="DIR",
-        help="persistent JAX compilation cache under DIR "
-        "(docs/design.md §14): compiled device programs — including the "
-        "panel tier's K-grid sub-launch set and the batch ladder — are "
-        "serialized to disk and replayed on restart, so geometry churn "
-        "stops paying the cold-compile tax per process. Also arms the "
-        "ladder pre-warm hook (the default geometry's power-of-two batch "
-        "programs compile at startup, off the serving path). Empty "
-        "disables",
     )
     p.add_argument(
         "-recv-dir",
@@ -326,14 +311,13 @@ def main(argv: list[str] | None = None) -> int:
     setup_logging()  # stderr-forced, like flag.Set("logtostderr") main.go:118
     args = build_parser().parse_args(argv)
 
-    compile_cache_armed = False
-    if args.compile_cache_dir and args.backend == "device":
+    if args.backend == "device":
         # Before the first jit: the cache decision is made once per
         # process, so arming it after a compile would strand that
         # program outside the cache.
-        from noise_ec_tpu.ops.dispatch import enable_compile_cache
+        from noise_ec_tpu.ops.dispatch import default_compile_cache
 
-        compile_cache_armed = enable_compile_cache(args.compile_cache_dir)
+        default_compile_cache()
 
     keys = KeyPair.random()  # fresh identity per run, main.go:132
     log.info("private key: %s", keys.private_key_hex())
@@ -407,10 +391,10 @@ def main(argv: list[str] | None = None) -> int:
     plugin = ShardPlugin(
         backend=args.backend, on_message=on_message, store=store
     )
-    # Compile the default geometry before traffic arrives; with the
-    # persistent cache armed, also pre-warm the batch ladder so every
-    # expected program lands in (or replays from) the on-disk cache.
-    plugin.prewarm(ladder=8 if compile_cache_armed else 0)
+    # Compile the default geometry before traffic arrives; a device node
+    # also pre-warms the batch ladder so every expected program lands in
+    # (or replays from) the persistent compile cache.
+    plugin.prewarm(ladder=8)
     net.add_plugin(plugin)
 
     rebalancer = None

@@ -429,7 +429,7 @@ class ShardPlugin:
         ``ladder > 1`` additionally pre-warms the power-of-two batch
         ladder up to that size (the coalescer's quantized batch
         programs, ops/dispatch.prewarm_ladder) — paired with the
-        persistent compile cache (-compile-cache-dir) so a restart
+        persistent compile cache (default_compile_cache) so a restart
         replays the whole program set from disk instead of recompiling
         it under live traffic.
         """

@@ -87,29 +87,39 @@ _op_stats: dict[str, list] = {}
 _gauges_installed = False
 _live_high_water = 0
 
-# Peak HBM bandwidth by jax backend, GB/s. v5e ships 819 GB/s HBM2; the
-# CPU figure is a commodity-DDR ballpark so utilization still reads as a
-# sane 0..1 on the test backend. Override with set_peak_hbm_gbps.
-_PEAK_GBPS = {"tpu": 819.0, "gpu": 900.0, "cpu": 25.0}
+# Published per-chip peaks by ``device_kind`` (the string JAX reports as
+# ``jax.devices()[0].device_kind``). Source: Google Cloud documentation,
+# "TPU v5e" — 819 GB/s HBM2e bandwidth, 393 TOP/s int8, 16 GB HBM per
+# chip. A kind missing from the table has NO roofline (utilization reads
+# NaN) rather than a guessed denominator; set_peak_hbm_gbps pins one.
+DEVICE_PEAKS = {
+    "TPU v5 lite": {"hbm_gbps": 819.0, "int8_tops": 393.0},
+}
 _peak_override: Optional[float] = None
 
 
 def set_peak_hbm_gbps(gbps: Optional[float]) -> None:
     """Pin the roofline's peak-bandwidth denominator (None restores the
-    per-backend table — e.g. a v4 deployment sets 1228)."""
+    per-``device_kind`` table lookup)."""
     global _peak_override
     _peak_override = gbps
 
 
-def peak_hbm_gbps() -> float:
+def peak_hbm_gbps() -> Optional[float]:
+    """Peak HBM GB/s of the default device, or None when its kind is not
+    in :data:`DEVICE_PEAKS` (and no override is pinned)."""
     if _peak_override is not None:
         return _peak_override
-    try:
-        import jax
+    import jax
 
-        return _PEAK_GBPS.get(jax.default_backend(), 100.0)
-    except Exception:  # noqa: BLE001 — telemetry must not require jax
-        return 100.0
+    peaks = DEVICE_PEAKS.get(jax.devices()[0].device_kind)
+    return peaks["hbm_gbps"] if peaks else None
+
+
+def _utilization(gbps: float) -> float:
+    """Achieved GB/s over the peak; NaN when the device has no peak."""
+    peak = peak_hbm_gbps()
+    return gbps / peak if peak else float("nan")
 
 
 def dispatch_key(entry: str, kernel: str, M, shape: tuple) -> bytes:
@@ -269,7 +279,7 @@ def _install_utilization_gauge(entry: str,
     reg = registry if registry is not None else default_registry()
     try:
         reg.gauge("noise_ec_roofline_utilization").set_callback(
-            lambda e=entry: achieved_gbps(e) / max(peak_hbm_gbps(), 1e-9),
+            lambda e=entry: _utilization(achieved_gbps(e)),
             kernel=entry,
         )
     except Exception:  # noqa: BLE001 — a gauge must not fail a dispatch
@@ -344,9 +354,7 @@ def record_tile_dispatch(entry: str, tile: str, nbytes: int,
     if fresh:
         try:
             reg.gauge("noise_ec_kernel_tile_utilization").set_callback(
-                lambda e=entry, t=tile: (
-                    tile_achieved_gbps(e, t) / max(peak_hbm_gbps(), 1e-9)
-                ),
+                lambda e=entry, t=tile: _utilization(tile_achieved_gbps(e, t)),
                 entry=entry, tile=tile,
             )
         except Exception:  # noqa: BLE001 — telemetry must not raise
@@ -499,9 +507,8 @@ def roofline_summary() -> dict:
         a = achieved_gbps(entry)
         if a > 0:
             out[f"device_{entry}_achieved_gbps"] = round(a, 2)
-            out[f"device_{entry}_utilization"] = round(
-                a / max(peak_hbm_gbps(), 1e-9), 4
-            )
+            if peak_hbm_gbps():
+                out[f"device_{entry}_utilization"] = round(_utilization(a), 4)
     hbm = hbm_snapshot()
     if hbm:
         out["hbm_live_mib"] = round(hbm.get("live_bytes", 0) / 2**20, 1)

@@ -431,7 +431,7 @@ METRICS: dict[str, tuple[str, str, tuple[str, ...]]] = {
     ),
     "noise_ec_compile_cache_hits_total": (
         "counter",
-        "Persistent JAX compilation-cache hits (-compile-cache-dir): "
+        "Persistent JAX compilation-cache hits (default_compile_cache): "
         "programs a restart replayed from disk instead of recompiling",
         (),
     ),

@@ -41,17 +41,6 @@ from noise_ec_tpu.gf.field import GF
 MXU_TILE_WORDS = 512
 
 
-def _trace_state_clean() -> bool:
-    """True when no jax trace is active (private API with a conservative
-    fallback: treating the state as dirty only skips a cache promotion)."""
-    try:
-        from jax._src.core import trace_state_clean
-
-        return bool(trace_state_clean())
-    except Exception:  # noqa: BLE001 — API moved; assume tracing
-        return False
-
-
 def _mxu_kernel(r: int, k: int, kernel_tw: int, m2_ref, w_ref, o_ref):
     # Mosaic cannot reshape across the minor (lane) dim, so the u32 words
     # are never byte-deinterleaved: all 32 bits unpack along a NEW sublane
@@ -123,9 +112,11 @@ def cached_bit_expansion(cache: dict, gf: GF, M: np.ndarray,
         if len(cache) > bound:
             cache.clear()
         cache[key] = hit
-    if isinstance(hit, np.ndarray) and _trace_state_clean():
-        hit = jnp.asarray(hit)
-        cache[key] = hit
+    if isinstance(hit, np.ndarray):
+        dev = jnp.asarray(hit)
+        if isinstance(dev, jax.core.Tracer):
+            return dev  # under a trace: use it, never cache it
+        hit = cache[key] = dev
     return hit
 
 

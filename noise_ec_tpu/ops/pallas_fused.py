@@ -28,6 +28,7 @@ owns the decision). Reference hot loop: /root/reference/main.go:262.
 from __future__ import annotations
 
 import functools
+import logging
 
 import jax
 import jax.numpy as jnp
@@ -44,6 +45,8 @@ from noise_ec_tpu.ops.pallas_pack import (
     transpose_windows,
 )
 from noise_ec_tpu.ops.xor_factor import eval_bits_rows
+
+log = logging.getLogger("noise_ec_tpu.ops")
 
 # 1 MiB tighter than pallas_gf2mm's VMEM_BUDGET_BYTES: the fused kernel
 # additionally keeps delta-swap pack/unpack temporaries on the Mosaic stack,
@@ -329,7 +332,7 @@ def _dma_split_call(nets: tuple, r: int, TW: int, m: int, ksl: int,
     return pl.pallas_call(
         functools.partial(_dma_split_kernel, m, TL, rounds, nets, ksl),
         grid=(TW // (8 * m * TL),),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],  # stays in HBM
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],  # stays in HBM
         out_specs=pl.BlockSpec((r, 8 * m * TL), lambda c: (0, c),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((r, TW), jnp.uint32),
@@ -513,7 +516,11 @@ def _probe_compiles(bits_rows: tuple, k: int, r: int, m: int,
         shape = jax.ShapeDtypeStruct((k_pad, TW), jnp.uint32)
         jax.jit(call).lower(shape).compile()
         return True
-    except Exception:  # noqa: BLE001 — any compile failure disqualifies
+    except Exception as exc:  # noqa: BLE001 — any compile failure disqualifies
+        # Logged, not silent: an API break would otherwise demote every
+        # fused encode to the three-kernel or sublane tier unnoticed.
+        log.warning("fused plan %s (k=%d, r=%d, m=%d) failed to compile: "
+                    "%s: %s", cand, k, r, m, type(exc).__name__, exc)
         return False
 
 

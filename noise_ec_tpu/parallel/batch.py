@@ -35,7 +35,7 @@ from noise_ec_tpu.matrix.generators import generator_matrix
 from noise_ec_tpu.matrix.linalg import reconstruction_matrix
 from noise_ec_tpu.ops.bitops import pack_bitplanes_jax, unpack_bitplanes_jax
 from noise_ec_tpu.ops.gf2mm import gf2_matmul_jax
-from noise_ec_tpu.parallel.mesh import _shard_map_compat, mesh_router
+from noise_ec_tpu.parallel.mesh import _shard_map, mesh_router
 
 _FIELDS = {"gf256": GF256, "gf65536": GF65536}
 
@@ -269,7 +269,7 @@ class BatchCodec:
                 out = jax.lax.all_gather(out, row_axis, axis=1, tiled=True)
             return out
 
-        fn = _shard_map_compat(
+        fn = _shard_map(
             local, mesh,
             in_specs=(mask_spec, P(batch_axis, None, None)),
             out_specs=P(batch_axis, None, None),
@@ -496,13 +496,13 @@ class BatchCodec:
             return out
 
         if kernel == "xla":
-            fn = _shard_map_compat(
+            fn = _shard_map(
                 local_xla, mesh,
                 in_specs=(mask_spec, P(batch_axis, None, None)),
                 out_specs=P(batch_axis, None, None),
             )
             return functools.partial(jax.jit(fn), jnp.asarray(masks))
-        fn = _shard_map_compat(
+        fn = _shard_map(
             local_pallas, mesh,
             in_specs=(P(batch_axis, None, None),),
             out_specs=P(batch_axis, None, None),

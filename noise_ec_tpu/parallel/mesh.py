@@ -68,10 +68,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # JAX >= 0.4.35 exposes shard_map at top level
-    from jax import shard_map as _shard_map  # type: ignore[attr-defined]
-except ImportError:  # pragma: no cover - version compat
-    from jax.experimental.shard_map import shard_map as _shard_map
 
 __all__ = [
     "MeshRouter",
@@ -88,14 +84,11 @@ __all__ = [
 STRIPES_AXIS = "stripes"
 
 
-def _shard_map_compat(f, mesh, in_specs, out_specs):
-    """shard_map across JAX versions (check_rep -> check_vma rename)."""
-    try:
-        return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                          check_vma=False)
-    except TypeError:  # pragma: no cover - older JAX
-        return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                          check_rep=False)
+def _shard_map(f, mesh, in_specs, out_specs):
+    """jax.shard_map with the varying-axes check off (the local bodies
+    run opaque Pallas kernels the checker cannot type)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def make_mesh(
@@ -268,7 +261,7 @@ class MeshRouter:
                 return jax.vmap(single)(words_local)
 
             spec = P(STRIPES_AXIS, None, None)
-            f = _shard_map_compat(
+            f = _shard_map(
                 local, self.mesh_for(n_dev), in_specs=(spec,), out_specs=spec
             )
             if donate:
@@ -314,7 +307,7 @@ class MeshRouter:
 
             in_spec = P(STRIPES_AXIS, None, None)
             out_spec = P(STRIPES_AXIS, None)
-            f = _shard_map_compat(
+            f = _shard_map(
                 local, self.mesh_for(n_dev),
                 in_specs=(in_spec,), out_specs=(out_spec, out_spec),
             )

@@ -1,6 +1,7 @@
 """ctypes binding for the native RS codec shim.
 
-Loads ``librs_shim.so`` (building it with ``make`` on first use) and wraps
+Loads ``librs_shim.so`` (running ``make`` first, so the library is always
+the one built from the committed ``rs_shim.cpp``) and wraps
 the C ABI in the same shard-list surface as
 :class:`noise_ec_tpu.codec.rs.ReedSolomon`, so the native backend is a
 drop-in for the Python/NumPy path. The same .so is what a Go host would
@@ -28,13 +29,14 @@ _MATRIX_KINDS = {"cauchy": 0, "vandermonde": 1}
 
 
 def build_shim(force: bool = False) -> Path:
-    """Build librs_shim.so with make; returns its path."""
-    if force or not _SO_PATH.exists():
-        subprocess.run(
-            ["make", "-C", str(_SHIM_DIR)] + (["-B"] if force else []),
-            check=True,
-            capture_output=True,
-        )
+    """Run make on librs_shim.so and return its path. Always runs: make
+    is a no-op when the library is newer than its sources, and rebuilds
+    a stale copied binary from the committed sources otherwise."""
+    subprocess.run(
+        ["make", "-C", str(_SHIM_DIR)] + (["-B"] if force else []),
+        check=True,
+        capture_output=True,
+    )
     return _SO_PATH
 
 
@@ -91,8 +93,8 @@ def _load() -> ctypes.CDLL:
     if _lib is None:
         lib = ctypes.CDLL(str(build_shim()))
         if not hasattr(lib, "rs16_decode1_fused"):
-            # Stale prebuilt .so from before the ABI grew (build_shim only
-            # runs make when the file is MISSING): rebuild, then reopen
+            # Stale .so whose mtime still beats the sources (a copy that
+            # kept a newer timestamp): force the rebuild, then reopen
             # past the dlopen pathname cache — otherwise registering the
             # missing symbol below would fail the load and silently
             # disable EVERY native path.

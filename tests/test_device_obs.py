@@ -253,16 +253,19 @@ def test_maybe_analyze_is_rate_limited_per_entry():
 
 
 def test_peak_hbm_override():
-    from noise_ec_tpu.obs.device import set_peak_hbm_gbps
+    """Peaks key on device_kind: the CPU test backend is not in the
+    table, so it has no roofline until a denominator is pinned."""
+    from noise_ec_tpu.obs.device import DEVICE_PEAKS, set_peak_hbm_gbps
 
-    base = peak_hbm_gbps()
-    assert base > 0
+    assert DEVICE_PEAKS["TPU v5 lite"] == {"hbm_gbps": 819.0,
+                                           "int8_tops": 393.0}
+    assert peak_hbm_gbps() is None
     set_peak_hbm_gbps(1228.0)
     try:
         assert peak_hbm_gbps() == 1228.0
     finally:
         set_peak_hbm_gbps(None)
-    assert peak_hbm_gbps() == base
+    assert peak_hbm_gbps() is None
 
 
 # -- HBM accounting ---------------------------------------------------------
@@ -392,6 +395,11 @@ def test_xprof_endpoint_404_without_dir():
 # -- bench regression gate --------------------------------------------------
 
 
+# Synthetic BENCH_r*/MULTICHIP_r* records (not chip numbers) the gate
+# replays in place of a recorded chip trajectory.
+GATE_FIXTURES = Path(__file__).resolve().parent / "data" / "bench_gate"
+
+
 def _bench_gate():
     sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "tools"))
     try:
@@ -407,12 +415,12 @@ def test_bench_gate_directions_and_tolerances():
     assert bg.metric_direction("reconstruct3_1mib_p50_ms") == "down"
     assert bg.metric_direction("backend") is None
     assert bg.metric_direction("rs200_56_error") is None
-    # Gated again since the ISSUE-8 data-path rebuild (it slid 9.3 ->
-    # 3.1 MB/s while skipped): direction up, TIGHT device tolerance even
-    # though the host_ prefix would otherwise grant the load-tail one.
-    tunnel = "host_node_large_object_device_tunnel_mb_per_s"
-    assert bg.metric_direction(tunnel) == "up"
-    assert bg.metric_tolerance(tunnel) == bg.DEFAULT_TOLERANCE
+    # The device tier of the large-object stream: direction up, TIGHT
+    # device tolerance even though the host_ prefix would otherwise
+    # grant the load-tail one.
+    large = "host_node_large_object_device_mb_per_s"
+    assert bg.metric_direction(large) == "up"
+    assert bg.metric_tolerance(large) == bg.DEFAULT_TOLERANCE
     assert bg.metric_direction("device_matmul_words_achieved_gbps") is None
     assert bg.metric_tolerance("rs17_3_encode_gbps") < bg.metric_tolerance(
         "host_node_roundtrip_mb_per_s"
@@ -420,19 +428,19 @@ def test_bench_gate_directions_and_tolerances():
 
 
 def test_bench_gate_flags_synthetic_20pct_regression():
-    """Acceptance: a 20% throughput cut exits nonzero; the real r04->r05
-    series exits zero."""
+    """Acceptance: a 20% throughput cut exits nonzero; the recorded
+    r01->r02 fixture series exits zero."""
     bg = _bench_gate()
-    series = dict(bg.recorded_series())
-    r05 = series["BENCH_r05.json"]
-    cut = dict(r05)
-    cut["rs200_56_encode_gbps"] = r05["rs200_56_encode_gbps"] * 0.8
-    problems, findings = bg.gate(r05, cut)
+    series = dict(bg.recorded_series(GATE_FIXTURES))
+    r02 = series["BENCH_r02.json"]
+    cut = dict(r02)
+    cut["rs200_56_encode_gbps"] = r02["rs200_56_encode_gbps"] * 0.8
+    problems, findings = bg.gate(r02, cut)
     assert any("rs200_56_encode_gbps" in p for p in problems)
     regressed = [f for f in findings if f["regressed"]]
     assert [f["metric"] for f in regressed] == ["rs200_56_encode_gbps"]
 
-    problems, _ = bg.gate(series["BENCH_r04.json"], r05)
+    problems, _ = bg.gate(series["BENCH_r01.json"], r02)
     assert problems == []
 
 
@@ -440,37 +448,37 @@ def test_bench_gate_check_mode_passes():
     """The --check self-test (the tier-1 CI hook) replays the recorded
     series clean."""
     bg = _bench_gate()
-    assert bg.self_check(verbose=False) == []
-    assert bg.main(["--check"]) == 0
+    assert bg.self_check(verbose=False, repo=GATE_FIXTURES) == []
+    assert bg.main(["--check", "--repo", str(GATE_FIXTURES)]) == 0
 
 
 def test_bench_gate_cli_on_recorded_rounds():
     bg = _bench_gate()
-    root = str(Path(__file__).resolve().parent.parent)
+    root = GATE_FIXTURES
     assert bg.main([
-        "--current", f"{root}/BENCH_r05.json",
-        "--against", f"{root}/BENCH_r04.json",
+        "--current", f"{root}/BENCH_r02.json",
+        "--against", f"{root}/BENCH_r01.json",
     ]) == 0
     assert bg.main([
-        "--current", f"{root}/BENCH_r04.json",
-        "--against", f"{root}/BENCH_r05.json",
+        "--current", f"{root}/BENCH_r01.json",
+        "--against", f"{root}/BENCH_r02.json",
     ]) == 1  # the reversed diff is a genuine regression
 
 
 def test_bench_gate_wire_rig_bars():
     """ISSUE-11: the wire hot-loop rig bars (>= 50k msgs/s, roundtrip
     MB/s within 4x of the large-object host path) bite on rigs with a
-    recorded MULTICHIP round — this repo records one — and pass once
+    recorded MULTICHIP round — the fixture series records one — and pass once
     the loop clears them; dev-box-shaped numbers are flagged with the
     ROADMAP pointer."""
     bg = _bench_gate()
-    assert bg.newest_multichip_devices() > 1  # the recorded rig
+    assert bg.newest_multichip_devices(GATE_FIXTURES) > 1  # the recorded rig
     slow = {
         "host_node_roundtrip_msgs_per_s": 216.3,
         "host_node_roundtrip_mb_per_s": 14.2,
         "host_node_large_object_mb_per_s": 229.8,
     }
-    problems = bg.wire_rig_check(slow)
+    problems = bg.wire_rig_check(slow, GATE_FIXTURES)
     assert any("50000" in p for p in problems)
     assert any("4x" in p for p in problems)
     fast = {
@@ -478,7 +486,7 @@ def test_bench_gate_wire_rig_bars():
         "host_node_roundtrip_mb_per_s": 80.0,
         "host_node_large_object_mb_per_s": 229.8,
     }
-    assert bg.wire_rig_check(fast) == []
+    assert bg.wire_rig_check(fast, GATE_FIXTURES) == []
     # wire_ stats ride the host tolerance; the info keys carry no
     # direction (they describe amortization, not a perf contract).
     assert bg.metric_tolerance("wire_verify_batch_size_p50") == bg.HOST_TOLERANCE

@@ -4,6 +4,8 @@ repair / decode routes vs the single-device golden paths, uneven tail
 batches, the zero-reshard chained encode→decode contract, and the
 mid-batch device-fault fan-out through the codec breaker."""
 
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -310,11 +312,12 @@ def test_bench_gate_flags_mesh_devices_regression():
     rounds prove an 8-device mesh must flag on fresh runs; a healthy
     mesh (or a genuinely single-device rig) must not."""
     bg = _bench_gate()
-    assert bg.newest_multichip_devices() == 8  # the recorded rig
-    assert bg.mesh_rig_check({"batch_mesh_devices": 8}) == []
-    problems = bg.mesh_rig_check({"batch_mesh_devices": 1})
+    rig = Path(__file__).resolve().parent / "data" / "bench_gate"
+    assert bg.newest_multichip_devices(rig) == 8  # the fixture rig
+    assert bg.mesh_rig_check({"batch_mesh_devices": 8}, rig) == []
+    problems = bg.mesh_rig_check({"batch_mesh_devices": 1}, rig)
     assert problems and "mesh dispatch tier regressed" in problems[0]
-    assert bg.mesh_rig_check({}) != []  # sweep vanished entirely
+    assert bg.mesh_rig_check({}, rig) != []  # sweep vanished entirely
     # Tolerance classes: sweep keys ride the device gate, staged mesh
     # stats the host one.
     assert bg.metric_tolerance("batch_mesh_encode_gbps_8chip") == \

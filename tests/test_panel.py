@@ -501,13 +501,17 @@ def test_mesh_sublaunch_split_zero_reshard(rng, monkeypatch):
 
 
 def test_compile_cache_repeat_sweep_zero_recompile(rng, tmp_path):
-    """The compile-churn guard with the persistent cache armed: enable
-    -compile-cache-dir's backing hook, then a repeated panel geometry
-    sweep must add ZERO compile-route dispatches — and the cache dir
-    must hold serialized executables for the sweep's programs."""
-    from noise_ec_tpu.ops.dispatch import enable_compile_cache
+    """The compile-churn guard with the persistent cache armed: with
+    JAX_COMPILATION_CACHE_DIR placing the cache (config mirror of the
+    env var), default_compile_cache keeps that directory, a repeated
+    panel geometry sweep adds ZERO compile-route dispatches — and the
+    directory holds serialized executables for the sweep's programs."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
 
-    assert enable_compile_cache(str(tmp_path))
+    from noise_ec_tpu.ops.dispatch import default_compile_cache
+
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    assert default_compile_cache() == str(tmp_path)
     try:
         compiles = default_registry().counter("noise_ec_jit_compiles_total")
 
@@ -533,12 +537,29 @@ def test_compile_cache_repeat_sweep_zero_recompile(rng, tmp_path):
     finally:
         # Un-arm: later tests must not keep serializing into tmp_path.
         jax.config.update("jax_compilation_cache_dir", None)
-        try:
-            from jax._src import compilation_cache as _cc
+        cc.reset_cache()
 
-            _cc.reset_cache()
-        except Exception:  # noqa: BLE001 — best-effort teardown
-            pass
+
+def test_default_compile_cache_fixed_path_without_env(tmp_path,
+                                                     monkeypatch):
+    """No JAX_COMPILATION_CACHE_DIR: default_compile_cache places the
+    cache at the fixed in-checkout path (DEFAULT_CACHE_DIR — redirected
+    to tmp_path here so the test writes nothing into the repo) with the
+    size/time floors zeroed."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    import noise_ec_tpu.ops.dispatch as dispatch_mod
+
+    assert dispatch_mod.DEFAULT_CACHE_DIR.endswith(".jax_cache")
+    monkeypatch.setattr(dispatch_mod, "DEFAULT_CACHE_DIR", str(tmp_path))
+    jax.config.update("jax_compilation_cache_dir", None)
+    try:
+        assert dispatch_mod.default_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
+    finally:
+        jax.config.update("jax_compilation_cache_dir", None)
+        cc.reset_cache()
 
 
 def test_compile_cache_hit_counter():
@@ -598,29 +619,32 @@ def test_panel_rig_check_bars(tmp_path):
     record, the PR-10 bars bite — rs200_56 route off panel, encode
     under 150 GB/s, or a wide-field decode ratio over 1.25 each flag;
     a green run and a recordless dev box do not."""
+    from pathlib import Path
+
     bg = _bench_gate()
-    assert bg.newest_multichip_devices() == 8  # this repo records a rig
+    rig = Path(__file__).resolve().parent / "data" / "bench_gate"
+    assert bg.newest_multichip_devices(rig) == 8  # the fixture rig
     good = {
         "rs200_56_route": "panel",
         "rs200_56_sublaunches": 3,
         "rs200_56_encode_gbps": 163.0,
         "gf65536_vs_gf256_decode_ratio": 1.1,
     }
-    assert bg.panel_rig_check(good) == []
+    assert bg.panel_rig_check(good, rig) == []
     assert len(bg.panel_rig_check({
         "rs200_56_route": "mxu",
         "rs200_56_encode_gbps": 38.4,
         "gf65536_vs_gf256_decode_ratio": 1.6,
-    })) == 3
-    problems = bg.panel_rig_check(dict(good, rs200_56_encode_gbps=120.0))
+    }, rig)) == 3
+    problems = bg.panel_rig_check(dict(good, rs200_56_encode_gbps=120.0), rig)
     assert len(problems) == 1 and "150" in problems[0]
     problems = bg.panel_rig_check(
-        dict(good, gf65536_vs_gf256_decode_ratio=1.3)
+        dict(good, gf65536_vs_gf256_decode_ratio=1.3), rig
     )
     assert len(problems) == 1 and "1.25" in problems[0]
     # Missing keys (recorded pre-panel rounds) stay green; a dev box
     # without a MULTICHIP record is exempt entirely.
-    assert bg.panel_rig_check({}) == []
+    assert bg.panel_rig_check({}, rig) == []
     assert bg.panel_rig_check(
         {"rs200_56_route": "mxu"}, repo=tmp_path
     ) == []
