@@ -25,8 +25,8 @@ has to come from structured telemetry, not log archaeology:
 - :mod:`obs.health` — end-to-end outcome recording and the rolling SLO
   evaluator whose verdict drives ``/healthz``;
 - :mod:`obs.device` — device telemetry: per-dispatch latency with a
-  compile/execute split, recompile counters, roofline (cost_analysis
-  FLOPs/bytes, achieved-vs-peak utilization) and HBM gauges;
+  compile/execute split read from JAX's own compile events, recompile
+  counters, program cost (cost_analysis FLOPs/bytes) and HBM gauges;
 - :mod:`obs.sampler` — the always-on ~50 Hz folded-stack sampling
   profiler behind ``GET /profile``;
 - :mod:`obs.events` — the wide structured-event log: every

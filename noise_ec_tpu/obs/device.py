@@ -7,21 +7,24 @@ indistinguishable from a slow device, and memory headroom was invisible
 until an OOM. Four surfaces close that gap:
 
 - **Dispatch latency with a compile/execute split** —
-  :func:`device_op` wraps every ``DeviceCodec`` dispatch. The first call
-  for a (entry, kernel, matrix, shape) cache key is the one that traces
-  and compiles; it records as ``route="compile"`` into
-  ``noise_ec_device_op_seconds{kernel,route}`` and feeds
-  ``noise_ec_jit_compiles_total{kernel}`` plus the compile-seconds
-  histogram. Warm calls record as ``route="execute"`` on the
-  device-scale (half-octave, us-range) bucket set.
+  :func:`device_op` wraps every ``DeviceCodec`` dispatch and records it
+  into ``noise_ec_device_op_seconds{kernel,route}``. The route comes
+  from JAX's own events: one ``jax.monitoring`` listener
+  (:func:`install_compile_listener`) counts backend compiles per thread,
+  and a dispatch inside whose window JAX ran one records as
+  ``route="compile"`` and feeds ``noise_ec_jit_compiles_total{kernel}``
+  plus the compile-seconds histogram; every other dispatch — a program
+  JAX already holds, however new its key — records as
+  ``route="execute"``. The same listener lands JAX's trace and compile
+  durations as finished ``jax_trace`` / ``backend_compile`` spans on the
+  calling thread. While a ``jax.profiler`` session records, each
+  dispatch also opens a host ``TraceMe`` named ``dispatch``.
 - **Roofline** — :func:`analyze_program` pulls
   ``fn.lower(*args).compile().cost_analysis()`` FLOPs / bytes-accessed
   for a freshly compiled program (cheap: the AOT path reuses the jit
   compilation cache — measured ~17 ms after a 330 ms first call) and
-  exports per-kernel program-cost and operational-intensity gauges;
-  ``noise_ec_roofline_utilization{kernel}`` reads achieved payload
-  bandwidth (cumulative execute bytes / execute seconds) over
-  :func:`peak_hbm_gbps` at collect time.
+  exports per-kernel program-cost and operational-intensity gauges.
+  Achieved bandwidth is the device trace's to measure, not the host's.
 - **HBM accounting** — :func:`hbm_snapshot` sums ``jax.live_arrays()``
   and folds in the allocator's ``memory_stats()`` where the backend
   reports them (TPU does; CPU returns None and falls back to the
@@ -32,30 +35,28 @@ until an OOM. Four surfaces close that gap:
   :func:`~noise_ec_tpu.obs.profiling.device_trace` so a live node can
   capture a TensorBoard/xprof trace of a decode burst on demand.
 
-Hot-path budget: a warm dispatch pays one perf_counter pair, one set
-lookup and one cached-child histogram observe — the same cost class as
-the span layer, on a path whose cheapest op (a 14 us reconstruct) is
-~5x the overhead. Compile-route extras (cost analysis, gauge install)
-ride the first call only, which is seconds-scale anyway.
+Hot-path budget: a dispatch pays one perf_counter pair, two reads of a
+thread-local compile count, one profiler-enabled check and one
+cached-child histogram observe — no lock — on a path whose cheapest op
+(a 14 us reconstruct) is many times the overhead.
 """
 
 from __future__ import annotations
 
-import hashlib
 import logging
 import threading
 import time
 from typing import Optional
 
 from noise_ec_tpu.obs.registry import Registry, default_registry
+from noise_ec_tpu.obs.trace import default_tracer, host_traceme
 
 __all__ = [
     "DeviceOpTimer",
     "analyze_program",
-    "achieved_gbps",
     "device_op",
-    "dispatch_key",
     "hbm_snapshot",
+    "install_compile_listener",
     "install_hbm_gauges",
     "maybe_analyze_program",
     "peak_hbm_gbps",
@@ -71,27 +72,19 @@ __all__ = [
 log = logging.getLogger("noise_ec_tpu.obs")
 
 _lock = threading.Lock()
-# Dispatch cache keys already seen by this process: membership decides the
-# compile/execute route. Bounded like the dispatch-side caches — a clear
-# only means a few dispatches re-record as compiles.
-_seen_keys: set[bytes] = set()
-_SEEN_BOUND = 16384
 # (kernel, route) -> histogram child; kernel -> (counter, hist) children.
 # Default-registry only (the health.py pattern): a transient Registry must
 # not pin stale children.
 _op_children: dict[tuple[str, str], object] = {}
 _compile_children: dict[str, tuple] = {}
-# kernel -> [execute_bytes_total, execute_seconds_total] for the achieved-
-# bandwidth side of the roofline gauges.
-_op_stats: dict[str, list] = {}
 _gauges_installed = False
 _live_high_water = 0
 
 # Published per-chip peaks by ``device_kind`` (the string JAX reports as
 # ``jax.devices()[0].device_kind``). Source: Google Cloud documentation,
 # "TPU v5e" — 819 GB/s HBM2e bandwidth, 393 TOP/s int8, 16 GB HBM per
-# chip. A kind missing from the table has NO roofline (utilization reads
-# NaN) rather than a guessed denominator; set_peak_hbm_gbps pins one.
+# chip. A kind missing from the table has no peak (None) rather than a
+# guessed one; set_peak_hbm_gbps pins one.
 DEVICE_PEAKS = {
     "TPU v5 lite": {"hbm_gbps": 819.0, "int8_tops": 393.0},
 }
@@ -99,7 +92,7 @@ _peak_override: Optional[float] = None
 
 
 def set_peak_hbm_gbps(gbps: Optional[float]) -> None:
-    """Pin the roofline's peak-bandwidth denominator (None restores the
+    """Pin the peak-bandwidth figure (None restores the
     per-``device_kind`` table lookup)."""
     global _peak_override
     _peak_override = gbps
@@ -116,34 +109,64 @@ def peak_hbm_gbps() -> Optional[float]:
     return peaks["hbm_gbps"] if peaks else None
 
 
-def _utilization(gbps: float) -> float:
-    """Achieved GB/s over the peak; NaN when the device has no peak."""
-    peak = peak_hbm_gbps()
-    return gbps / peak if peak else float("nan")
-
-
-def dispatch_key(entry: str, kernel: str, M, shape: tuple) -> bytes:
-    """Stable cache key for one dispatch: the same (matrix bytes, stripe
-    shape, kernel entry) that decides whether jit re-traces. Matrix bytes
-    are digested — keys live in a process-wide set and generator matrices
-    reach (200, 256)."""
-    import numpy as np
-
-    h = hashlib.blake2b(digest_size=16)
-    h.update(entry.encode())
-    h.update(kernel.encode())
-    h.update(repr(shape).encode())
-    h.update(np.ascontiguousarray(M).tobytes())
-    return h.digest()
-
-
 def reset_dispatch_tracking() -> None:
-    """Forget seen dispatch keys and per-kernel stats (tests)."""
+    """Forget per-tile stats and analysis rate limits (tests)."""
     with _lock:
-        _seen_keys.clear()
-        _op_stats.clear()
         _tile_stats.clear()
         _last_analysis.clear()
+
+
+# --------------------------------------------- JAX's compile events
+
+# The duration events JAX records around a jit trace and a backend
+# compile (jax._src.dispatch); a persistent-cache load is timed under
+# the backend-compile event too, since it stands in for one.
+JAXPR_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_EVENT_SPANS = {
+    JAXPR_TRACE_EVENT: "jax_trace",
+    BACKEND_COMPILE_EVENT: "backend_compile",
+}
+# Backend compiles run so far on each thread: a dispatch window compares
+# the count at its entry and exit.
+_compiles = threading.local()
+_listener_installed = False
+
+
+def _compiles_on_thread() -> int:
+    return getattr(_compiles, "n", 0)
+
+
+def _on_jax_duration(event: str, duration: float, **kwargs) -> None:
+    """jax.monitoring duration listener: JAX calls it on the thread that
+    traced or compiled, as the work finishes."""
+    stage = _EVENT_SPANS.get(event)
+    if stage is None:
+        return
+    if event == BACKEND_COMPILE_EVENT:
+        _compiles.n = _compiles_on_thread() + 1
+    fun = kwargs.get("fun_name")
+    if fun is None:
+        default_tracer().record(stage, duration)
+    else:
+        default_tracer().record(stage, duration, fun=str(fun))
+
+
+def install_compile_listener() -> None:
+    """Register the JAX-event listener once per process (idempotent).
+    :func:`device_op` calls it, so every dispatching process routes by
+    what JAX did."""
+    global _listener_installed
+    if _listener_installed:
+        return
+    with _lock:
+        if _listener_installed:
+            return
+        from jax import monitoring
+
+        monitoring.register_event_duration_secs_listener(_on_jax_duration)
+        default_tracer().declare(*_EVENT_SPANS.values())
+        _listener_installed = True
 
 
 class DeviceOpTimer:
@@ -156,45 +179,40 @@ class DeviceOpTimer:
     dispatch that runs a block-panel kernel sets it to the plan's
     ``tile_label`` (e.g. ``kb128_rb32_tl512``) before the window
     closes, and the exit path feeds the ``noise_ec_kernel_tile_*``
-    families — so the roofline gain (or loss) of an auto-tuned tile
-    triple is attributable per config, not hidden in the aggregate
-    kernel series.
+    counters, so dispatches and bytes are attributable per config.
     """
 
-    __slots__ = ("entry", "key", "nbytes", "registry", "route", "elapsed",
-                 "tile", "_t0")
+    __slots__ = ("entry", "nbytes", "registry", "route", "elapsed",
+                 "tile", "_t0", "_n0", "_tm")
 
-    def __init__(self, entry: str, key: bytes, nbytes: int,
+    def __init__(self, entry: str, nbytes: int,
                  registry: Optional[Registry]):
         self.entry = entry
-        self.key = key
         self.nbytes = nbytes
         self.registry = registry
         self.route = ""
         self.elapsed = 0.0
         self.tile = ""
 
+    def compiled(self) -> bool:
+        """True once JAX has run a backend compile on this thread inside
+        the window (readable mid-dispatch, after the program call)."""
+        return _compiles_on_thread() != self._n0
+
     def __enter__(self) -> "DeviceOpTimer":
-        with _lock:
-            if self.key in _seen_keys:
-                self.route = "execute"
-            else:
-                if len(_seen_keys) >= _SEEN_BOUND:
-                    _seen_keys.clear()
-                _seen_keys.add(self.key)
-                self.route = "compile"
+        self._tm = host_traceme("dispatch")
+        self._n0 = _compiles_on_thread()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         self.elapsed = time.perf_counter() - self._t0
+        if self._tm is not None:
+            self._tm.__exit__(None, None, None)
+            self._tm = None
+        self.route = "compile" if self.compiled() else "execute"
         if exc is not None:
-            # A failed dispatch must not poison the split: the next call
-            # for this key is the one that will actually compile.
-            if self.route == "compile":
-                with _lock:
-                    _seen_keys.discard(self.key)
-            return False
+            return False  # a failed dispatch records nothing
         reg = self.registry
         if reg is None:
             op = _op_children.get((self.entry, self.route))
@@ -211,14 +229,6 @@ class DeviceOpTimer:
         op.observe(self.elapsed)
         if self.route == "compile":
             self._record_compile(reg)
-        else:
-            with _lock:
-                st = _op_stats.get(self.entry)
-                if st is None:
-                    st = _op_stats[self.entry] = [0.0, 0.0]
-                    _install_utilization_gauge(self.entry, reg)
-                st[0] += self.nbytes
-                st[1] += self.elapsed
         if self.tile:
             record_tile_dispatch(
                 self.entry, self.tile, self.nbytes, self.elapsed,
@@ -252,50 +262,26 @@ class DeviceOpTimer:
         pair[1].observe(self.elapsed)
 
 
-def device_op(entry: str, key: bytes, nbytes: int = 0,
+def device_op(entry: str, nbytes: int = 0,
               registry: Optional[Registry] = None) -> DeviceOpTimer:
-    """``with device_op("matmul_words", key, nbytes):`` around one
-    DeviceCodec dispatch. Also installs the HBM gauges on first use so
-    any process that dispatches exports memory headroom."""
+    """``with device_op("matmul_words", nbytes):`` around one
+    DeviceCodec dispatch. Also installs the JAX compile listener and the
+    HBM gauges on first use, so any process that dispatches routes by
+    JAX's events and exports memory headroom."""
+    if not _listener_installed:
+        install_compile_listener()
     install_hbm_gauges(registry)
-    return DeviceOpTimer(entry, key, nbytes, registry)
-
-
-# ------------------------------------------------------------------ roofline
-
-
-def achieved_gbps(entry: str) -> float:
-    """Cumulative execute-route payload bandwidth for one kernel entry
-    (0.0 until a warm dispatch lands)."""
-    with _lock:
-        st = _op_stats.get(entry)
-    if not st or st[1] <= 0:
-        return 0.0
-    return st[0] / st[1] / 1e9
-
-
-def _install_utilization_gauge(entry: str,
-                               registry: Optional[Registry]) -> None:
-    reg = registry if registry is not None else default_registry()
-    try:
-        reg.gauge("noise_ec_roofline_utilization").set_callback(
-            lambda e=entry: _utilization(achieved_gbps(e)),
-            kernel=entry,
-        )
-    except Exception:  # noqa: BLE001 — a gauge must not fail a dispatch
-        log.debug("roofline gauge install failed for %s", entry)
+    return DeviceOpTimer(entry, nbytes, registry)
 
 
 # -------------------------------------------------- per-tile attribution
 #
 # The block-panel kernels are auto-tuned: the planner picks a
-# (KB, RB, TL) tile triple per geometry from the VMEM cost model, and
-# the triple is part of the dispatch cache key — but a cache key is
-# invisible on /metrics. These families make the chosen config a LABEL,
-# so "did the auto-tuner's pick actually deliver" is answerable per tile
-# config: dispatch/byte counters plus an achieved-bandwidth-over-peak
-# utilization gauge per (kernel entry, tile), the tile-resolved view of
-# noise_ec_roofline_utilization.
+# (KB, RB, TL) tile triple per geometry from the VMEM cost model — but
+# the choice is invisible on /metrics. These counters make the chosen
+# config a LABEL (dispatches and bytes per (kernel entry, tile)), and
+# tile_summary() folds the host-timed per-tile bandwidth into bench.py's
+# report.
 
 # (entry, tile) -> [execute_bytes_total, execute_seconds_total]
 _tile_stats: dict[tuple[str, str], list] = {}
@@ -343,22 +329,10 @@ def record_tile_dispatch(entry: str, tile: str, nbytes: int,
     pair[1].add(nbytes)
     if route != "execute":
         return
-    reg = registry if registry is not None else default_registry()
     with _lock:
-        st = _tile_stats.get((entry, tile))
-        fresh = st is None
-        if fresh:
-            st = _tile_stats[(entry, tile)] = [0.0, 0.0]
+        st = _tile_stats.setdefault((entry, tile), [0.0, 0.0])
         st[0] += nbytes
         st[1] += seconds
-    if fresh:
-        try:
-            reg.gauge("noise_ec_kernel_tile_utilization").set_callback(
-                lambda e=entry, t=tile: _utilization(tile_achieved_gbps(e, t)),
-                entry=entry, tile=tile,
-            )
-        except Exception:  # noqa: BLE001 — telemetry must not raise
-            log.debug("tile gauge install failed for %s/%s", entry, tile)
 
 
 def tile_summary() -> dict:
@@ -478,6 +452,8 @@ def install_hbm_gauges(registry: Optional[Registry] = None) -> None:
     default registry; explicit registries always install)."""
     global _gauges_installed
     if registry is None:
+        if _gauges_installed:
+            return
         with _lock:
             if _gauges_installed:
                 return
@@ -498,17 +474,8 @@ def install_hbm_gauges(registry: Optional[Registry] = None) -> None:
 
 
 def roofline_summary() -> dict:
-    """Flat dict for bench/report output: per-kernel achieved GB/s and
-    utilization plus the HBM snapshot (MiB)."""
+    """Flat dict for bench/report output: the HBM snapshot (MiB)."""
     out: dict = {}
-    with _lock:
-        entries = list(_op_stats)
-    for entry in entries:
-        a = achieved_gbps(entry)
-        if a > 0:
-            out[f"device_{entry}_achieved_gbps"] = round(a, 2)
-            if peak_hbm_gbps():
-                out[f"device_{entry}_utilization"] = round(_utilization(a), 4)
     hbm = hbm_snapshot()
     if hbm:
         out["hbm_live_mib"] = round(hbm.get("live_bytes", 0) / 2**20, 1)

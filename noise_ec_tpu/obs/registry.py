@@ -78,6 +78,22 @@ PIPELINE_STAGES: tuple[str, ...] = (
     # Single-flight followers: the span that points a coalesced reader
     # at its leader's trace.
     "joined",
+    # Device dispatch split (ops/dispatch.py): the program call through
+    # block_until_ready on its output, then the copy back to the host.
+    "device_wait",
+    "readback",
+    # The codec's part of a degraded read (store/stripe.py read).
+    "reconstruct",
+    # Waits: a coalescer leader's linger and a follower's wait for its
+    # batch (attr role), a contended DeviceGate admission, a contended
+    # StripeStore lock.
+    "coalesce_wait",
+    "gate_wait",
+    "store_lock_wait",
+    # JAX's own trace and backend-compile durations (obs/device.py's
+    # jax.monitoring listener), landed as finished spans.
+    "jax_trace",
+    "backend_compile",
 )
 
 # name -> (type, help, label names). The single source of truth for every
@@ -369,21 +385,22 @@ METRICS: dict[str, tuple[str, str, tuple[str, ...]]] = {
     "noise_ec_device_op_seconds": (
         "histogram",
         "Per-dispatch device codec latency, labeled by kernel entry and "
-        "route (compile = first call for a (matrix, shape, kernel) cache "
-        "key, execute = warm calls). Words entries time the async submit; "
-        "stripes entries time through host materialization",
+        "route (compile = JAX ran a backend compile inside the dispatch, "
+        "execute = it ran a program JAX already held). Words entries time "
+        "the async submit; stripes entries time through host "
+        "materialization",
         ("kernel", "route"),
     ),
     "noise_ec_jit_compiles_total": (
         "counter",
-        "First-call dispatches per (matrix, shape, kernel) cache key — "
-        "geometry churn causing recompiles shows here as a rate instead "
-        "of a silent p99 cliff",
+        "Dispatches inside which JAX ran a backend compile (its "
+        "jax.monitoring events) — geometry churn causing recompiles shows "
+        "here as a rate instead of a silent p99 cliff",
         ("kernel",),
     ),
     "noise_ec_jit_compile_seconds": (
         "histogram",
-        "Latency of first-call (trace + compile + run) dispatches, "
+        "Latency of the dispatches that compiled (trace + compile + run), "
         "labeled by kernel entry",
         ("kernel",),
     ),
@@ -404,14 +421,6 @@ METRICS: dict[str, tuple[str, str, tuple[str, ...]]] = {
         "counter",
         "Payload bytes dispatched per (entry, tile config) on the "
         "block-panel kernels",
-        ("entry", "tile"),
-    ),
-    "noise_ec_kernel_tile_utilization": (
-        "gauge",
-        "Achieved execute-route payload bandwidth over the device peak "
-        "(0..1) per (entry, tile config) — the tile-resolved view of "
-        "noise_ec_roofline_utilization that attributes a wide-geometry "
-        "gain to the panel plan that produced it",
         ("entry", "tile"),
     ),
     "noise_ec_kernel_sublaunch_dispatches_total": (
@@ -476,13 +485,6 @@ METRICS: dict[str, tuple[str, str, tuple[str, ...]]] = {
         "gauge",
         "Operational intensity (cost_analysis FLOPs / bytes accessed) of "
         "the most recently compiled program, labeled by kernel entry",
-        ("kernel",),
-    ),
-    "noise_ec_roofline_utilization": (
-        "gauge",
-        "Achieved payload bandwidth over the device peak (0..1), from "
-        "cumulative execute-route dispatch bytes/seconds, labeled by "
-        "kernel entry",
         ("kernel",),
     ),
     "noise_ec_profile_samples_total": (

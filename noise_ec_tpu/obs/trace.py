@@ -32,6 +32,12 @@ Overhead per span: two clock reads, one deque append under a lock, one
 histogram observe — per *message stage*, not per kernel call, so the
 encode hot loop (``record_kernel``) keeps its two counter adds.
 
+Spans on the profiler's clock: while a ``jax.profiler`` session records,
+every span also opens a host ``TraceMe`` of its own name (nested on the
+thread as the span is), so a device trace's host plane shows which
+program stages were in flight at any instant. With no session the cost
+is one ``TraceMe.is_enabled()`` call per span.
+
 Request-scoped tracing (docs/observability.md "Request tracing"): a
 user-facing op opens :func:`request`, which mints a ``req-<16 hex>``
 trace id, roots a ``request`` span, and — unlike signature-keyed
@@ -53,6 +59,7 @@ propagation headers and frame attrs.
 
 from __future__ import annotations
 
+import sys
 import threading
 import time
 from collections import deque
@@ -69,6 +76,7 @@ __all__ = [
     "clock_anchor",
     "current_trace_id",
     "default_tracer",
+    "host_traceme",
     "node_attrs",
     "request",
     "span",
@@ -105,6 +113,31 @@ def clock_anchor() -> dict:
     return {"wall": _WALL0, "perf": _PERF0, "now": time.time()}
 
 
+# jax.profiler.TraceAnnotation, bound on first use once jax is imported:
+# a profiler session needs jax, so before that no span can be recorded
+# and the tracer never imports jax itself.
+_TraceMe = None
+
+
+def host_traceme(name: str):
+    """A started profiler ``TraceMe`` named ``name`` while a
+    ``jax.profiler`` session records, else None. The caller closes what
+    it got (``tm.__exit__(None, None, None)``)."""
+    global _TraceMe
+    tm = _TraceMe
+    if tm is None:
+        if "jax" not in sys.modules:
+            return None
+        from jax.profiler import TraceAnnotation as tm
+
+        _TraceMe = tm
+    if not tm.is_enabled():
+        return None
+    t = tm(name)
+    t.__enter__()
+    return t
+
+
 class Span:
     """One live (then finished) stage timing. Mutable until exit.
 
@@ -114,7 +147,7 @@ class Span:
 
     __slots__ = (
         "name", "key", "attrs", "parent", "start", "end",
-        "trace_id", "error", "seq", "_tracer",
+        "trace_id", "error", "seq", "_tracer", "_tm",
     )
 
     def __init__(self, tracer: "Tracer", name: str, key: Optional[str],
@@ -129,17 +162,22 @@ class Span:
         self.trace_id: Optional[str] = None
         self.error: Optional[str] = None
         self.seq = 0
+        self._tm = None
 
     def __enter__(self) -> "Span":
         stack = self._tracer._stack()
         if stack:
             self.parent = stack[-1]
         stack.append(self)
+        self._tm = host_traceme(self.name)
         self.start = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         self.end = time.perf_counter()
+        if self._tm is not None:
+            self._tm.__exit__(None, None, None)
+            self._tm = None
         if exc is not None:
             self.error = repr(exc)
         tracer = self._tracer
@@ -432,21 +470,49 @@ class Tracer:
         """Short node id (``address#pk8``) or '' when unset."""
         return self.node["id"] if self.node is not None else ""
 
-    def _record_stage(self, sp: Span) -> None:
-        reg = self._registry if self._registry is not None else default_registry()
-        if self._stage_hist is None:
-            self._stage_hist = reg.histogram("noise_ec_stage_seconds")
-            self._span_counter = reg.counter("noise_ec_spans_total")
+    def _stage_pair(self, name: str) -> tuple:
         # Cache children per stage name: labels() is a lock + dict get,
         # and span exit is on the delivery path.
-        pair = self._stage_children.get(sp.name)
+        pair = self._stage_children.get(name)
         if pair is None:
-            pair = self._stage_children[sp.name] = (
-                self._stage_hist.labels(stage=sp.name),
-                self._span_counter.labels(stage=sp.name),
+            if self._stage_hist is None:
+                reg = (self._registry if self._registry is not None
+                       else default_registry())
+                self._stage_hist = reg.histogram("noise_ec_stage_seconds")
+                self._span_counter = reg.counter("noise_ec_spans_total")
+            pair = self._stage_children[name] = (
+                self._stage_hist.labels(stage=name),
+                self._span_counter.labels(stage=name),
             )
+        return pair
+
+    def _record_stage(self, sp: Span) -> None:
+        pair = self._stage_pair(sp.name)
         pair[0].observe(sp.seconds)
         pair[1].add(1)
+
+    def declare(self, *names: str) -> None:
+        """Create the stage series of ``names`` at zero, so a stage that
+        has not happened yet (a lock never contended) reads 0 s instead
+        of no series at all."""
+        for name in names:
+            self._stage_pair(name)
+
+    def record(self, name: str, seconds: float, **attrs) -> None:
+        """Land a span that has already finished: ``seconds`` long,
+        ending now, a child of the span open on this thread. JAX's
+        compile events arrive this way (obs/device.py)."""
+        if not self.enabled:
+            return
+        sp = Span(self, name, None, attrs)
+        stack = self._stack()
+        if stack:
+            sp.parent = stack[-1]
+        sp.end = time.perf_counter()
+        sp.start = sp.end - seconds
+        sp.trace_id = sp._resolve_trace_id(self)
+        self._land(sp)
+        self._record_stage(sp)
 
     def span(self, name: str, key: Optional[str] = None, **attrs):
         """Time a stage: ``with tracer.span("decode", key=...) as sp``.

@@ -42,7 +42,10 @@ Metrics: ``noise_ec_coalesce_batches_total``,
 ``noise_ec_coalesce_flush_reason_total{reason}`` and
 ``noise_ec_coalesce_batch_size`` (one observation PER MEMBER request —
 the distribution answers "what batch size did a request ride", so a p50
-above 1 means most requests were amortized).
+above 1 means most requests were amortized). The time a request spends
+waiting here is the ``coalesce_wait`` span: a leader's linger
+(``role="leader"``) and a follower's wait for its batch
+(``role="follower"``).
 """
 
 from __future__ import annotations
@@ -51,6 +54,8 @@ import threading
 import time
 from contextlib import contextmanager
 from typing import Callable, Optional, Sequence
+
+from noise_ec_tpu.obs.trace import default_tracer, span
 
 __all__ = [
     "CoalescingDispatcher",
@@ -175,6 +180,7 @@ class CoalescingDispatcher:
             ).labels(reason=reason)
             for reason in ("solo", "linger", "full", "bulk", "shared")
         }
+        default_tracer().declare("coalesce_wait")
 
     # ------------------------------------------------------------- submit
 
@@ -331,12 +337,13 @@ class CoalescingDispatcher:
     def _lead(self, bucket: _Bucket, linger: float,
               reason: Optional[str] = None) -> None:
         if linger > 0:
-            deadline = time.monotonic() + linger
-            while time.monotonic() < deadline:
-                with self._lock:
-                    if len(bucket.payloads) >= self.max_batch:
-                        break
-                time.sleep(min(0.0002, linger))
+            with span("coalesce_wait", role="leader"):
+                deadline = time.monotonic() + linger
+                while time.monotonic() < deadline:
+                    with self._lock:
+                        if len(bucket.payloads) >= self.max_batch:
+                            break
+                    time.sleep(min(0.0002, linger))
         with self._lock:
             bucket.closed = True
             if self._buckets.get(bucket.key) is bucket:
@@ -371,7 +378,9 @@ class CoalescingDispatcher:
             raise bucket.error
 
     def _await(self, bucket: _Bucket, idx: int):
-        if not bucket.done.wait(_FOLLOWER_TIMEOUT_S):
+        with span("coalesce_wait", role="follower"):
+            done = bucket.done.wait(_FOLLOWER_TIMEOUT_S)
+        if not done:
             raise RuntimeError(
                 "coalesced dispatch never completed (leader lost)"
             )

@@ -47,12 +47,9 @@ from noise_ec_tpu.ops.pallas_gf2mm import (
     planes_to_tiled,
     tiled_to_planes,
 )
-from noise_ec_tpu.obs.device import (
-    device_op,
-    dispatch_key,
-    maybe_analyze_program,
-)
+from noise_ec_tpu.obs.device import device_op, maybe_analyze_program
 from noise_ec_tpu.obs.profiling import record_kernel
+from noise_ec_tpu.obs.trace import default_tracer, span
 from noise_ec_tpu.ops.coalesce import QOS_LANES, current_qos
 
 _FIELDS = {"gf256": GF256, "gf65536": GF65536}
@@ -165,8 +162,11 @@ def ensure_codec_prober() -> None:
 
 
 def _probe_loop() -> None:
-    br = codec_breaker()
     while True:
+        # Read the breaker on every pass: configure_codec_breaker may have
+        # replaced the one this thread started for, and while this thread
+        # lives ensure_codec_prober starts no other.
+        br = codec_breaker()
         if br.closed:
             return
         remaining = br.open_remaining()
@@ -333,6 +333,7 @@ class DeviceGate:
             lane: reg.counter("noise_ec_lane_grants_total").labels(lane=lane)
             for lane in QOS_LANES
         }
+        default_tracer().declare("gate_wait")
 
     def acquire(self) -> None:
         lane, tenant, weight = current_qos()
@@ -355,11 +356,12 @@ class DeviceGate:
             deadline = t0 + self.wait_timeout
             self.waiters += 1
             try:
-                while not ticket.granted:
-                    remaining = deadline - time.monotonic()
-                    if remaining <= 0:
-                        break  # governor, not a deadlock: proceed
-                    self._cv.wait(min(remaining, 0.5))
+                with span("gate_wait", lane=lane):
+                    while not ticket.granted:
+                        remaining = deadline - time.monotonic()
+                        if remaining <= 0:
+                            break  # governor, not a deadlock: proceed
+                        self._cv.wait(min(remaining, 0.5))
             finally:
                 self.waiters -= 1
                 if not ticket.granted:
@@ -628,6 +630,17 @@ def donation_supported() -> bool:
         return False
 
 
+def _ready(out):
+    """Wait for a dispatched program's output — the end of a
+    ``device_wait`` span — with its copy to the host already started, so
+    the transfer out overlaps the thread's return to Python. Waiting
+    first and starting the copy only in ``np.array`` added about 0.8 ms
+    to each 10 MiB encode dispatch on a TPU v5e host with four writer
+    threads: the transfer waited for the interpreter lock."""
+    out.copy_to_host_async()
+    return jax.block_until_ready(out)
+
+
 @functools.lru_cache(maxsize=256)
 def _fused_xla_fn(degree: int, r: int, k: int, S: int):
     """Compiled (masks, shards) -> product stripes, shape-generic kernel."""
@@ -803,9 +816,10 @@ def _panel_words_pipeline(r_rows: int, m: int, bits_rows: tuple,
 def _panel_words_fn(r_rows: int, m: int, bits_rows: tuple, plan: tuple,
                     interpret: bool, donate: bool = False):
     """Jitted panel-tier words entry: (k, TW) u32 -> (r_rows, TW) u32
-    with the (KB, RB, TL) plan baked (the plan is part of the program —
-    and of the dispatch cache key, so a plan change is a visible
-    recompile, not a silent one)."""
+    with the (KB, RB, TL) plan baked (the plan is part of the program,
+    so a plan change builds a new one, and the dispatch that compiles
+    it records as ``route="compile"`` — a visible recompile, not a
+    silent one)."""
     return _jit_words(
         _panel_words_pipeline(r_rows, m, bits_rows, plan, interpret),
         donate,
@@ -1212,18 +1226,6 @@ class DeviceCodec:
         plan = self.panel_plan_for(M)
         return ("panel", plan) if plan is not None else ("mxu", None)
 
-    def _key_shape(self, M: np.ndarray, shape: tuple) -> tuple:
-        """Dispatch-cache key shape: panel-routed matrices append the
-        (KB, RB, TL) tile triple AND the sub-launch count G, so a plan
-        change (auto-tuner update, probe escalation, demotion) reads as
-        a compile-route dispatch in the telemetry instead of silently
-        re-timing under the old key."""
-        if self.kernel != "xla":
-            route, plan = self._route_plan(M)
-            if route == "panel":
-                return shape + ("panel",) + plan[:3] + (plan[4],)
-        return shape
-
     def _m2_for_wide(self, M: np.ndarray):
         """Cached (16r, 16k) int8 bit expansion of a gf65536 matrix for
         the byte-sliced MXU route (shared implementation — see
@@ -1263,11 +1265,13 @@ class DeviceCodec:
         """(r, k) GF matrix x (k, S) stripes -> (r, S), computed on device.
 
         Device-telemetry wrapper: every dispatch lands in
-        ``noise_ec_device_op_seconds{kernel,route}`` — the first call per
-        (matrix, shape, kernel) cache key as ``route="compile"`` (feeding
-        the recompile counter), warm calls as ``route="execute"``. This
-        entry materializes the result on host, so the timing covers the
-        device round trip, not just the async submit (obs/device.py).
+        ``noise_ec_device_op_seconds{kernel,route}`` — as
+        ``route="compile"`` when JAX compiled inside it (feeding the
+        recompile counter), else ``route="execute"``. This entry
+        materializes the result on host, so the timing covers the device
+        round trip, not just the async submit (obs/device.py); inside
+        it, ``device_wait`` spans the program call through
+        ``block_until_ready`` and ``readback`` the copy to the host.
         """
         M = np.asarray(M)
         D = np.asarray(D, dtype=self.gf.dtype)
@@ -1276,10 +1280,9 @@ class DeviceCodec:
             raise ValueError(f"matrix cols {k} != stripe rows {D.shape[0]}")
         entry = f"matmul_stripes_{self.kernel}"
         record_kernel(entry, D.nbytes)
-        key = dispatch_key(entry, self.kernel, M, self._key_shape(M, D.shape))
         # Bounded device queue: admission BEFORE the telemetry window so
         # a gated wait reads as backpressure, not kernel latency.
-        with device_gate(), device_op(entry, key, nbytes=D.nbytes) as dt:
+        with device_gate(), device_op(entry, nbytes=D.nbytes) as dt:
             return self._matmul_stripes_dispatch(M, D, dt)
 
     def _matmul_stripes_dispatch(self, M: np.ndarray, D: np.ndarray,
@@ -1289,17 +1292,19 @@ class DeviceCodec:
         m = self.gf.degree
         if self.kernel == "xla":
             fn = _fused_xla_fn(m, r, k, S)
-            masks_dev = jnp.asarray(self.masks_for(M))
-            D_dev = jnp.asarray(D)
-            out = fn(masks_dev, D_dev)
-            if dt.route == "compile":
+            with span("device_wait"):
+                masks_dev = jnp.asarray(self.masks_for(M))
+                D_dev = jnp.asarray(D)
+                out = _ready(fn(masks_dev, D_dev))
+            if dt.compiled():
                 # Roofline: cost_analysis of the freshly cached program
                 # (rate-limited per entry — the AOT walk is not free and
                 # must not ride a geometry-churn storm).
                 maybe_analyze_program(dt.entry, fn, masks_dev, D_dev)
             # np.array (copy) so callers get an ordinary writable ndarray,
             # not a read-only view of the device buffer.
-            return np.array(out)
+            with span("readback"):
+                return np.array(out)
         if m == 16:
             # PACKED BYTE-SLICED GF(2^16): each u16 symbol splits into
             # ADJACENT (lo, hi) byte rows (the packed (2k, S) panel —
@@ -1354,23 +1359,28 @@ class DeviceCodec:
                 r, self.bits_rows_for(M),
                 self.kernel == "pallas_interpret", True,
             )
-        words_dev = jax.device_put(words)
-        if donation_supported():
-            buffer_pool().donate(words_dev)
-        # np.array: writable copy (np.asarray of a jax array is read-only
-        # and callers are promised an ordinary ndarray).
-        out_w = np.array(fn(words_dev))
+        # device_wait: transfer in, queue and kernel; readback below is
+        # the rest of the transfer out and the host copies.
+        with span("device_wait"):
+            words_dev = jax.device_put(words)
+            if donation_supported():
+                buffer_pool().donate(words_dev)
+            out = _ready(fn(words_dev))
         if lease is not None:
-            # Output materialized => the H2D copy is long done; the
-            # staging page is safe to hand to the next dispatch.
+            # Output ready => the H2D copy is long done; the staging page
+            # is safe to hand to the next dispatch.
             buffer_pool().release(lease)
-        if dt.route == "compile":
+        if dt.compiled():
             # ShapeDtypeStruct, not the live array: the input was donated
             # and must not be touched again.
             maybe_analyze_program(
                 dt.entry, fn, jax.ShapeDtypeStruct(words.shape, words.dtype)
             )
-        return np.ascontiguousarray(out_w.view(self.gf.dtype)[:, :S])
+        # np.array: writable copy (np.asarray of a jax array is read-only
+        # and callers are promised an ordinary ndarray).
+        with span("readback"):
+            out_w = np.array(out)
+            return np.ascontiguousarray(out_w.view(self.gf.dtype)[:, :S])
 
     def matmul_stripes_many(self, M: np.ndarray, Ds: list) -> list:
         """B same-shape stripes products through ONE gated dispatch.
@@ -1411,11 +1421,7 @@ class DeviceCodec:
         entry = f"matmul_stripes_{self.kernel}"
         nbytes = sum(D.nbytes for D in Ds)
         record_kernel(entry, nbytes)
-        key = dispatch_key(
-            entry, self.kernel, M,
-            self._key_shape(M, (B_pad,) + Ds[0].shape),
-        )
-        with device_gate(), device_op(entry, key, nbytes=nbytes) as dt:
+        with device_gate(), device_op(entry, nbytes=nbytes) as dt:
             if self.kernel != "xla" and self.gf.degree == 8:
                 return self._stripes_many_words(M, Ds, B_pad, dt)
             # Mesh dispatch tier (parallel/mesh.py, docs/design.md §13):
@@ -1457,10 +1463,12 @@ class DeviceCodec:
         for b, D in enumerate(Ds):
             buf[b * k : (b + 1) * k, :S] = D
         words = buf.view("<u4").reshape(B_pad, k, TWp)
-        out_w = np.array(self._matmul_words_batch_dispatch(M, words, dt))
+        with span("device_wait"):
+            out = _ready(self._matmul_words_batch_dispatch(M, words, dt))
         buffer_pool().release(lease)
-        res = out_w.view(self.gf.dtype)  # (B_pad, r, 4*TWp) symbols
-        return [np.ascontiguousarray(res[b, :, :S]) for b in range(B)]
+        with span("readback"):
+            res = np.array(out).view(self.gf.dtype)  # (B_pad, r, 4*TWp)
+            return [np.ascontiguousarray(res[b, :, :S]) for b in range(B)]
 
     def syndrome_stripes(
         self, A: np.ndarray, rows: np.ndarray
@@ -1672,12 +1680,8 @@ class DeviceCodec:
         # Async-entry caveat: this path returns a device array without
         # materializing, so the execute-route timing is the submit cost;
         # the compile route still times the synchronous trace+compile.
-        key = dispatch_key(
-            "matmul_words", self.kernel, M,
-            self._key_shape(M, tuple(words.shape)),
-        )
         # Same bounded-queue admission as matmul_stripes (device gate).
-        with device_gate(), device_op("matmul_words", key, nbytes=nbytes) as dt:
+        with device_gate(), device_op("matmul_words", nbytes=nbytes) as dt:
             return self._matmul_words_batch_dispatch(
                 M, words, dt, donate=donate
             )
@@ -1755,7 +1759,7 @@ class DeviceCodec:
         else:
             shape0 = jax.ShapeDtypeStruct(words.shape[1:], words.dtype)
             out = jax.vmap(fn)(words)
-        if dt.route == "compile":
+        if dt.compiled():
             # Best-effort: the MXU partial has no .lower and a traced
             # call passes tracers; the analysis degrades to None. Shape
             # struct, not the live array — it may have been donated.

@@ -18,9 +18,10 @@ plus the stored sender signature when available). Degraded reads use
 trusted shards only.
 
 Thread safety: one lock guards the stripe table and every stripe
-mutation; codec construction happens outside it. Disk writes are atomic
-(tmp + rename) so a torn write can never leave a wrong-content shard
-under a content-derived name.
+mutation; codec construction happens outside it. A thread that finds
+the lock held records its wait as a ``store_lock_wait`` span. Disk
+writes are atomic (tmp + rename) so a torn write can never leave a
+wrong-content shard under a content-derived name.
 """
 
 from __future__ import annotations
@@ -40,7 +41,7 @@ import numpy as np
 from noise_ec_tpu.codec.lrc import codec_for_code, parse_code
 from noise_ec_tpu.codec.rs import ReedSolomon
 from noise_ec_tpu.obs.registry import default_registry
-from noise_ec_tpu.obs.trace import trace_key
+from noise_ec_tpu.obs.trace import default_tracer, span, trace_key
 
 __all__ = [
     "DegradedReadError",
@@ -153,6 +154,28 @@ class _StoreMetrics:
         )
 
 
+class _StoreLock:
+    """The stripe table's mutex. An acquire that finds it free costs one
+    non-blocking try; one that finds it held is timed as a
+    ``store_lock_wait`` span."""
+
+    __slots__ = ("_lock",)
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        default_tracer().declare("store_lock_wait")
+
+    def __enter__(self) -> "_StoreLock":
+        if not self._lock.acquire(False):
+            with span("store_lock_wait"):
+                self._lock.acquire()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self._lock.release()
+        return False
+
+
 class StripeStore:
     """Content-addressed stripe store (see module docstring).
 
@@ -171,7 +194,7 @@ class StripeStore:
         self.store_dir = store_dir
         self.backend = backend
         self.max_stripes = max_stripes
-        self._lock = threading.Lock()
+        self._lock = _StoreLock()
         self._stripes: dict[str, _Stripe] = {}
         # Object manifests (service/objects.py): content address ->
         # manifest document. The stripe table holds codewords; this
@@ -569,7 +592,8 @@ class StripeStore:
             )
         self._metrics.degraded_reads.add(1)
         rs = self.codec(k, meta.n, meta.field, meta.code)
-        full = rs.reconstruct_data(usable)
+        with span("reconstruct"):
+            full = rs.reconstruct_data(usable)
         return rs.join(full, meta.object_len)
 
     def classify(self, key: str) -> Optional[str]:

@@ -16,7 +16,6 @@ import pytest
 from noise_ec_tpu.obs.device import (
     analyze_program,
     device_op,
-    dispatch_key,
     hbm_snapshot,
     peak_hbm_gbps,
 )
@@ -64,28 +63,32 @@ def test_device_buckets_are_us_range_and_finer_than_host():
 
 
 def _fresh_geometries(rng, n, k=4, r=2):
-    """n distinct full-rank-ish GF matrices unlikely to collide with any
-    other test's dispatch keys (random bytes, odd stripe width)."""
+    """n distinct (matrix, stripe) geometries whose programs no other
+    test compiles: random GF matrices over odd stripe widths (the XLA
+    kernel takes the matrix as an argument, so the width is what makes
+    each one a new program)."""
     from noise_ec_tpu.matrix.generators import generator_matrix
     from noise_ec_tpu.gf.field import GF256
 
     gf = GF256()
-    mats = []
-    for _ in range(n):
+    out = []
+    for i in range(n):
         M = np.asarray(
             generator_matrix(gf, k, k + r, "cauchy")[k:], dtype=np.uint8
         ).copy()
         # Random XOR salt keeps the matrix bytes unique per call while
         # staying a valid GF(2^8) linear map for encode purposes.
         M ^= rng.integers(1, 255, size=M.shape, dtype=np.uint8)
-        mats.append(M)
-    return mats
+        D = rng.integers(0, 256, size=(k, 229 + 6 * i)).astype(np.uint8)
+        out.append((M, D))
+    return out
 
 
 def test_geometry_churn_advances_compile_counter_exactly_once_per_key(rng):
     """The acceptance bar: N distinct geometries -> the recompile counter
-    advances exactly N; repeat dispatches advance it zero times while the
-    execute-route histogram keeps observing."""
+    advances exactly N (JAX compiled inside each first dispatch); repeat
+    dispatches advance it zero times while the execute-route histogram
+    keeps observing."""
     from noise_ec_tpu.ops.dispatch import DeviceCodec
 
     dev = DeviceCodec(field="gf256", kernel="xla")
@@ -97,13 +100,12 @@ def test_geometry_churn_advances_compile_counter_exactly_once_per_key(rng):
     exec_before = ops.labels(kernel=entry, route="execute").count
 
     N = 3
-    mats = _fresh_geometries(rng, N)
-    D = rng.integers(0, 256, size=(4, 224)).astype(np.uint8)
-    for M in mats:
+    geoms = _fresh_geometries(rng, N)
+    for M, D in geoms:
         dev.matmul_stripes(M, D)
     assert _child_value(compiles, kernel=entry) - before == N
 
-    for M in mats:  # same geometries again: zero new compiles
+    for M, D in geoms:  # same geometries again: zero new compiles
         dev.matmul_stripes(M, D)
         dev.matmul_stripes(M, D)
     assert _child_value(compiles, kernel=entry) - before == N
@@ -113,18 +115,24 @@ def test_geometry_churn_advances_compile_counter_exactly_once_per_key(rng):
 
 
 def test_failed_dispatch_does_not_consume_the_compile_slot():
-    """A dispatch that raises must leave the key unseen: the NEXT call is
-    the one that compiles, and the split must say so."""
-    key = dispatch_key("testfail", "xla", np.arange(4, dtype=np.uint8), (1,))
+    """A dispatch that raises records nothing: the NEXT call is the one
+    that compiles, and the split must say so."""
+    import jax
+    import jax.numpy as jnp
+
+    fn = jax.jit(lambda x: x * 3 + 1)  # a fresh function: a new program
+    x = jnp.arange(7)
     reg = Registry()
+    ops = reg.histogram("noise_ec_device_op_seconds")
     with pytest.raises(RuntimeError):
-        with device_op("testfail", key, nbytes=1, registry=reg):
+        with device_op("testfail", nbytes=1, registry=reg):
             raise RuntimeError("boom")
-    with device_op("testfail", key, nbytes=1, registry=reg) as dt:
-        pass
+    assert sum(child.count for _, child in ops.children()) == 0
+    with device_op("testfail", nbytes=1, registry=reg) as dt:
+        np.asarray(fn(x))
     assert dt.route == "compile"
-    with device_op("testfail", key, nbytes=1, registry=reg) as dt:
-        pass
+    with device_op("testfail", nbytes=1, registry=reg) as dt:
+        np.asarray(fn(x))
     assert dt.route == "execute"
 
 
@@ -142,7 +150,9 @@ def test_device_roundtrip_serves_op_seconds_on_metrics(rng):
     pa, pb = ShardPlugin(backend="device"), ShardPlugin(backend="device")
     a.add_plugin(pa)
     b.add_plugin(pb)
-    payload = bytes(rng.integers(0, 256, size=4096, dtype=np.uint8))
+    # An object size no other test dispatches: its encode program is new
+    # to the process, so the first dispatch compiles.
+    payload = bytes(rng.integers(0, 256, size=4452, dtype=np.uint8))
     pa.shard_and_broadcast(a, payload)
     assert pb.counters.get("verified") == 1
 
@@ -153,7 +163,7 @@ def test_device_roundtrip_serves_op_seconds_on_metrics(rng):
 
     # Same geometry + same payload size (distinct bytes: replay
     # protection dedups identical payloads) -> zero new compiles.
-    payload2 = bytes(rng.integers(0, 256, size=4096, dtype=np.uint8))
+    payload2 = bytes(rng.integers(0, 256, size=4452, dtype=np.uint8))
     pa.shard_and_broadcast(a, payload2)
     assert pb.counters.get("verified") == 2
     assert {key: c.value for key, c in compiles.children()} == flat_before

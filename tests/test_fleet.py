@@ -363,6 +363,7 @@ def test_small_fleet_acceptance_mixed_traffic_under_named_chaos(lockgraph):
     99.9% with shed-with-Retry-After counted separately from lost —
     plus the live /fleet route and the /healthz fleet block."""
     from noise_ec_tpu.obs.server import StatsServer
+    from noise_ec_tpu.ops.coalesce import configure_coalescer
 
     prof = FleetProfile.parse(
         "peers=50,fanout=6,msgs=150,chat=0.9,object=0.1,"
@@ -372,6 +373,16 @@ def test_small_fleet_acceptance_mixed_traffic_under_named_chaos(lockgraph):
     lab.start()
     server = StatsServer()
     lab.attach(server)
+    # Batching made independent of thread scheduling: inside the hot
+    # window (60 s: any submit following another thread's) a bucket
+    # leader holds its bucket open until a second request joins — the
+    # pair fills max_batch and flushes at once — or 50 ms pass. The
+    # default 0.5 ms linger paired requests only when the scheduler let
+    # the second thread reach the coalescer in time, about half of the
+    # runs on a loaded host.
+    configure_coalescer(
+        linger_seconds=0.05, max_batch=2, hot_window_seconds=60.0
+    )
     try:
         with urlopen(f"{server.url}/metrics", timeout=5) as resp:
             co_before = _exposition_hist_buckets(
@@ -418,6 +429,7 @@ def test_small_fleet_acceptance_mixed_traffic_under_named_chaos(lockgraph):
         assert fleet_block["up"] == 50
         assert fleet_block["delivered"] > 0
     finally:
+        configure_coalescer()
         server.close()
         lab.close()
 

@@ -416,7 +416,8 @@ def test_sublaunch_probe_escalation_and_final_demotion(monkeypatch):
 def test_sublaunch_dispatch_telemetry_and_cache_key(rng, monkeypatch):
     """A panel dispatch under a G-way plan is byte-identical through
     the public entry, adds G to the sub-launch dispatch counter, and
-    G is part of the dispatch cache key (a G change reads as a
+    G is part of the program's cache key (a G change builds a new
+    program, so its first dispatch compiles and reads as a
     compile-route dispatch, not a silent re-time)."""
     import noise_ec_tpu.ops.dispatch as dispatch_mod
 
@@ -429,8 +430,8 @@ def test_sublaunch_dispatch_telemetry_and_cache_key(rng, monkeypatch):
     monkeypatch.setattr(
         dispatch_mod, "panel_plan", lambda bits_rows, C: forced
     )
-    key1 = dev._key_shape(G[k:], (k, 3001))
-    assert key1[-1] == 2  # G rides the cache key tail
+    bits = dev.bits_rows_for(G[k:])
+    prog1 = dispatch_mod._panel_words_fn(r, 8, bits, forced, True, True)
     D = rng.integers(0, 256, size=(k, 3001)).astype(np.uint8)
     subs = default_registry().counter(
         "noise_ec_kernel_sublaunch_dispatches_total"
@@ -449,8 +450,10 @@ def test_sublaunch_dispatch_telemetry_and_cache_key(rng, monkeypatch):
     monkeypatch.setattr(
         dispatch_mod, "panel_plan", lambda bits_rows, C: base[:4] + (3,)
     )
-    key2 = dev._key_shape(G[k:], (k, 3001))
-    assert key2 != key1 and key2[-1] == 3
+    prog2 = dispatch_mod._panel_words_fn(
+        r, 8, bits, base[:4] + (3,), True, True
+    )
+    assert prog2 is not prog1  # G rides the program cache key
 
 
 def test_mesh_sublaunch_split_zero_reshard(rng, monkeypatch):

@@ -268,6 +268,43 @@ def test_codec_breaker_degradation_and_half_open_probe(monkeypatch):
     assert fec.encode_shares(data)[5].data == shares[5].data
 
 
+def test_codec_prober_follows_a_replaced_breaker(monkeypatch):
+    """A prober still waiting out a replaced breaker's long timeout does
+    not strand the breaker that replaced it: a trip of the new breaker
+    heals once the fault clears, though no second prober may start while
+    the first lives."""
+    from noise_ec_tpu.codec.rs import ReedSolomon
+    from noise_ec_tpu.ops import dispatch
+
+    def boom(self, M, Ds):
+        raise RuntimeError("injected device fault")
+
+    D = np.arange(4 * 16, dtype=np.uint8).reshape(4, 16)
+    old = dispatch.configure_codec_breaker(
+        reset_timeout=60.0, max_reset_timeout=120.0
+    )
+    try:
+        with monkeypatch.context() as mp:
+            mp.setattr(dispatch.DeviceCodec, "matmul_stripes_many", boom)
+            rs = ReedSolomon(4, 2)
+            rs.matmul_many(rs.G[4:], [D, D])
+        assert old.state() == "open"  # its prober now waits 60 s
+        br = dispatch.configure_codec_breaker(
+            reset_timeout=0.2, max_reset_timeout=1.0
+        )
+        with monkeypatch.context() as mp:
+            mp.setattr(dispatch.DeviceCodec, "matmul_stripes_many", boom)
+            rs = ReedSolomon(4, 2)
+            rs.matmul_many(rs.G[4:], [D, D])
+        assert br.state() == "open"
+        deadline = time.time() + 20
+        while time.time() < deadline and not br.closed:
+            time.sleep(0.05)
+        assert br.closed, br.snapshot()
+    finally:
+        dispatch.configure_codec_breaker()  # fresh, closed, for later tests
+
+
 # ------------------------------------------------------- NACK shard repair
 
 
