@@ -497,12 +497,16 @@ class ShardPlugin:
         )
         # The origin keeps its own object too: anti-entropy repair
         # (store/repair.py) can then serve any peer that rots, and the
-        # sender's stripe is the fleet's ground-truth copy.
+        # sender's stripe is the fleet's ground-truth copy. The shares
+        # just encoded ARE that stripe (the FEC and the store's "rs"
+        # codec share one Cauchy generator), so they are stored as they
+        # are, not encoded again.
         self._store_put_raw(
             shards[0].file_signature, input_bytes,
             int(shards[0].minimum_needed_shards),
             int(shards[0].total_shards),
             network.id.address, bytes(network.keys.public_key),
+            shards=[s.shard_data for s in shards],
         )
         with span(
             "broadcast",
@@ -1374,19 +1378,33 @@ class ShardPlugin:
 
     def _store_put_raw(
         self, file_signature: bytes, data, k: int, n: int,
-        address: str, public_key: bytes,
+        address: str, public_key: bytes, *, shards: Optional[list] = None,
     ) -> None:
+        """``shards``: the object's n shares, already encoded by this
+        plugin's FEC — stored as they are instead of re-encoding
+        ``data``."""
         if self.store is None:
             return
         try:
-            self.store.put_object(
-                file_signature,
-                bytes(data),
-                k,
-                n,
-                sender_address=address,
-                sender_public_key=public_key,
-            )
+            if shards is None:
+                self.store.put_object(
+                    file_signature,
+                    bytes(data),
+                    k,
+                    n,
+                    sender_address=address,
+                    sender_public_key=public_key,
+                )
+            else:
+                self.store.put_encoded(
+                    file_signature,
+                    bytes(data),
+                    shards,
+                    k,
+                    n,
+                    sender_address=address,
+                    sender_public_key=public_key,
+                )
             self.counters.add("store_puts", 1)
         except Exception as exc:  # noqa: BLE001 — delivery must proceed
             self.counters.add("store_put_errors", 1)

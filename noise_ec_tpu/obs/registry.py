@@ -227,6 +227,12 @@ METRICS: dict[str, tuple[str, str, tuple[str, ...]]] = {
         "Wire shards rejected by the absorb consistency check",
         (),
     ),
+    "noise_ec_store_puts_total": (
+        "counter",
+        "Stripes stored whole, labeled by encode: computed (put_object "
+        "encoded them) or reused (put_encoded kept the caller's shards)",
+        ("encode",),
+    ),
     "noise_ec_store_scrub_cycles_total": (
         "counter",
         "Completed scrub cycles",

@@ -9,13 +9,14 @@ readable while up to n-k shards are missing (reconstructed on demand —
 the degraded-read path).
 
 Trust model (mirrors the plugin's): shards written by :meth:`put_object`
-come from a signature-verified object and are *trusted*. Shards absorbed
-from the wire (:meth:`note_shard`, the anti-entropy fill path) are
-verified against the trusted remainder when >= k trusted shards exist
-(reconstruct-and-compare); otherwise they are held *unverified* until the
-repair engine can validate the whole stripe (error-correcting decode,
-plus the stored sender signature when available). Degraded reads use
-trusted shards only.
+come from a signature-verified object and are *trusted*, as are those
+the origin installs with :meth:`put_encoded` from its own encode of the
+object it signed. Shards absorbed from the wire (:meth:`note_shard`,
+the anti-entropy fill path) are verified against the trusted remainder
+when >= k trusted shards exist (reconstruct-and-compare); otherwise they
+are held *unverified* until the repair engine can validate the whole
+stripe (error-correcting decode, plus the stored sender signature when
+available). Degraded reads use trusted shards only.
 
 Thread safety: one lock guards the stripe table and every stripe
 mutation; codec construction happens outside it. A thread that finds
@@ -141,6 +142,12 @@ class _StoreMetrics:
         self.absorb_rejected = reg.counter(
             "noise_ec_store_absorb_rejected_total"
         ).labels()
+        self.puts = {
+            encode: reg.counter("noise_ec_store_puts_total").labels(
+                encode=encode
+            )
+            for encode in ("computed", "reused")
+        }
         cls = _StoreMetrics
         # Re-registered on every construction (idempotent — the closures
         # read the CLASS WeakSet): the test-isolation registry reset
@@ -203,7 +210,7 @@ class StripeStore:
         # the stripes so a restart restores the whole object space.
         self._manifests: dict[str, dict] = {}
         # Put listeners: called (key, data, meta) after every successful
-        # put_object — the object service absorbs replicated manifests
+        # put — the object service absorbs replicated manifests
         # through this hook (a verified receive lands here via the
         # plugin before any listener sees it).
         self._put_listeners: list[Callable] = []
@@ -243,8 +250,9 @@ class StripeStore:
 
     def add_put_listener(self, fn: Callable) -> None:
         """Register ``fn(key, data, meta)`` to run after every successful
-        :meth:`put_object` (outside the store lock; exceptions are logged,
-        never raised — a listener must not break the put path)."""
+        :meth:`put_object` or :meth:`put_encoded` (outside the store
+        lock; exceptions are logged, never raised — a listener must not
+        break the put path)."""
         self._put_listeners.append(fn)
 
     def add_delete_listener(self, fn: Callable) -> None:
@@ -282,10 +290,66 @@ class StripeStore:
             np.ascontiguousarray(s).view(np.uint8).tobytes()
             for s in rs.encode(rs.split(data))
         ]
+        return self._install(
+            file_signature, data, shards, k, "computed", field=field,
+            code=code, sender_address=sender_address,
+            sender_public_key=sender_public_key,
+        )
+
+    def put_encoded(
+        self,
+        file_signature: bytes,
+        data: bytes,
+        shards: list,
+        k: int,
+        n: int,
+        *,
+        field: str = "gf256",
+        code: str = "rs",
+        sender_address: str = "",
+        sender_public_key: bytes = b"",
+    ) -> str:
+        """Install ``data`` as a stripe the caller has already encoded;
+        returns the store key. Same trusted stripe, replacement rule and
+        put listeners as :meth:`put_object`, without its encode.
+
+        Contract: ``shards`` are the n shards of ``data`` zero-padded to
+        ``k * shard_len``, encoded by the caller with this store's own
+        codec for ``(k, n, field, code)`` — the bytes :meth:`put_object`
+        would store. Only the shape is checked: n shards of one non-zero
+        length and ``0 < len(data) <= k * shard_len``. ``bytes`` shards
+        are kept as given, not copied."""
+        if not 1 <= k <= n:
+            raise ValueError(f"invalid geometry k={k} n={n}")
+        parse_code(code)
+        if len(shards) != n:
+            raise ValueError(f"expected {n} shards, got {len(shards)}")
+        shards = [bytes(s) for s in shards]  # the same object for bytes
+        shard_len = len(shards[0])
+        if not shard_len or any(len(s) != shard_len for s in shards):
+            raise ValueError("shards must share one non-zero length")
+        if not 0 < len(data) <= k * shard_len:
+            raise ValueError(
+                f"object of {len(data)} bytes outside (0, k * shard_len = "
+                f"{k * shard_len}]"
+            )
+        return self._install(
+            file_signature, data, shards, k, "reused", field=field,
+            code=code, sender_address=sender_address,
+            sender_public_key=sender_public_key,
+        )
+
+    def _install(
+        self, file_signature: bytes, data: bytes, shards: list, k: int,
+        encode: str, *, field: str, code: str, sender_address: str,
+        sender_public_key: bytes,
+    ) -> str:
+        """The shared tail of both puts: store ``shards`` as one trusted
+        stripe of ``data``, persist it and run the put listeners."""
         meta = StripeMeta(
             file_signature=bytes(file_signature),
             k=k,
-            n=n,
+            n=len(shards),
             shard_len=len(shards[0]),
             object_len=len(data),
             field=field,
@@ -304,6 +368,7 @@ class StripeStore:
                 )
             self._replace_locked(meta.key, stripe)
         self._persist_stripe(stripe)
+        self._metrics.puts[encode].add(1)
         for fn in list(self._put_listeners):
             try:
                 fn(meta.key, data, meta)
